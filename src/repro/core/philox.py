@@ -131,6 +131,13 @@ def random_bits_like(x, key0, key1, counter_hi=0, counter_base=0):
     return flat.reshape(x.shape)
 
 
+def int_words(v: int) -> tuple[np.uint32, np.uint32]:
+    """The ``(lo, hi)`` uint32 words of a Python int, as ``derive_key``
+    reads it: jitted callers pass these to keep one compile per shape."""
+    v = int(v)
+    return np.uint32(v & 0xFFFFFFFF), np.uint32((v >> 32) & 0xFFFFFFFF)
+
+
 def derive_key(seed, stream):
     """Stateless (key0, key1) derivation from a seed and a stream id.
 
@@ -147,9 +154,7 @@ def derive_key(seed, stream):
             return (jnp.asarray(lo).astype(jnp.uint32),
                     jnp.asarray(hi).astype(jnp.uint32))
         if isinstance(v, (int, np.integer)):
-            v = int(v)
-            return (jnp.uint32(v & 0xFFFFFFFF),
-                    jnp.uint32((v >> 32) & 0xFFFFFFFF))
+            return split(int_words(v))
         return jnp.asarray(v).astype(jnp.uint32), jnp.uint32(0)
 
     s_lo, s_hi = split(seed)

@@ -39,6 +39,7 @@ against tampering, not stronger secrecy.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -176,25 +177,38 @@ def qpow_scalar(a, e: int):
     return r_hi, r_lo
 
 
-def gpow(exponent):
+def gpow(exponent, *, unroll: bool = False):
     """Fixed-base exponentiation ``h^s`` for uint32 exponents < p.
 
-    Data-dependent square-and-multiply is replaced by 31 precomputed
-    powers ``h^(2^i)`` and per-bit selects — fully vectorized over the
+    Data-dependent square-and-multiply is replaced by the 31 powers
+    ``h^(2^i)`` and per-bit selects — fully vectorized over the
     exponent array (this is the per-element hot loop of both commitment
     generation and share verification).
+
+    By default the 31 steps run as a ``fori_loop`` that squares the
+    scalar power as it goes: unrolled, XLA's CPU compiler takes minutes
+    on the chain.  ``unroll=True`` (the compiled TPU kernel) reads the
+    powers from the precomputed table instead.  Both give the same bits.
     """
     s = _u32(exponent)
-    acc_hi = jnp.zeros_like(s)
-    acc_lo = jnp.full_like(s, 1)
-    for i in range(31):
-        m_hi, m_lo = qmul((acc_hi, acc_lo),
-                          (_GEN_POW[i, 0], _GEN_POW[i, 1]))
-        bit = (s >> np.uint32(i)) & np.uint32(1)
-        take = bit != 0
-        acc_hi = jnp.where(take, m_hi, acc_hi)
-        acc_lo = jnp.where(take, m_lo, acc_lo)
-    return acc_hi, acc_lo
+
+    def step(i, acc, g):
+        m_hi, m_lo = qmul(acc, g)
+        take = ((s >> i) & np.uint32(1)) != 0
+        return jnp.where(take, m_hi, acc[0]), jnp.where(take, m_lo, acc[1])
+
+    acc = (jnp.zeros_like(s), jnp.full_like(s, 1))
+    if unroll:
+        for i in range(31):
+            acc = step(np.uint32(i), acc, (_GEN_POW[i, 0], _GEN_POW[i, 1]))
+        return acc
+
+    def body(i, carry):
+        acc, g = carry
+        return step(i.astype(jnp.uint32), acc, g), qmul(g, g)
+
+    gen = (jnp.uint32(_GEN_POW[0, 0]), jnp.uint32(_GEN_POW[0, 1]))
+    return jax.lax.fori_loop(0, 31, body, (acc, gen))[0]
 
 
 def pack(hi, lo):

@@ -118,7 +118,9 @@ def assign_home(party_ids, committee, seed: int,
     if any(i < 0 for i in ids):
         raise ValueError(f"negative party id in cohort: {ids[0]}")
     k0, k1 = philox.derive_key(seed, HOME_STREAM)
+    # a power-of-two draw: word i is the same whatever the length, and
+    # the cohort's largest id then picks one of few compiled shapes
     bits = np.asarray(philox.random_bits(
-        ids[-1] + 1, k0, k1,
+        1 << ids[-1].bit_length(), k0, k1,
         counter_hi=HOME_COUNTER_HI + round_index))
     return {i: members[int(bits[i]) % len(members)] for i in ids}

@@ -27,11 +27,11 @@ from repro.core import vss
 
 
 def _verify_kernel(rows_ref, commits_ref, ok_ref, *, k: int, c: int,
-                   points: tuple[int, ...]):
+                   points: tuple[int, ...], unroll: bool):
     c_hi = [commits_ref[j, 0, :, :] for j in range(c)]
     c_lo = [commits_ref[j, 1, :, :] for j in range(c)]
     for i in range(k):
-        lhs_hi, lhs_lo = vss.gpow(rows_ref[i, :, :])
+        lhs_hi, lhs_lo = vss.gpow(rows_ref[i, :, :], unroll=unroll)
         acc = (c_hi[c - 1], c_lo[c - 1])
         for j in range(c - 2, -1, -1):
             acc = vss.qpow_scalar(acc, points[i])
@@ -50,8 +50,11 @@ def verify_shares_pallas(rows, commits, points: tuple[int, ...],
     assert r % block_rows == 0
     assert k == len(points)
     c = commits.shape[0]
+    # interpret mode lowers the body to XLA's CPU compiler, which takes
+    # minutes on the unrolled exponentiation ladder (core.vss.gpow)
     kernel = functools.partial(_verify_kernel, k=k, c=c,
-                               points=tuple(int(p) for p in points))
+                               points=tuple(int(p) for p in points),
+                               unroll=not interpret)
     return pl.pallas_call(
         kernel,
         grid=(r // block_rows,),

@@ -295,6 +295,9 @@ class Coordinator:
             self.log(f"party {conn.pid} stream error: {e!r}")
         finally:
             self._mark_dead(conn)
+            # a connection nobody closes keeps Server.wait_closed()
+            # (Python >= 3.12) and so stop() waiting forever
+            conn.writer.close()
 
     def _mark_dead(self, conn: "_Conn | None") -> None:
         """EOF/error on ``conn``; a superseded connection (its pid

@@ -26,6 +26,7 @@ import os
 import subprocess
 import sys
 import threading
+import warnings
 
 import jax.numpy as jnp
 import numpy as np
@@ -238,8 +239,10 @@ class WireTransport(Transport):
             try:
                 asyncio.run_coroutine_threadsafe(
                     self.coordinator.stop(), self._loop).result(10)
-            except Exception:
-                pass
+            except Exception as e:
+                msg = f"coordinator stop did not finish: {e!r}"
+                self._log(msg)
+                warnings.warn(msg, RuntimeWarning, stacklevel=2)
         for proc in self._procs:
             try:
                 proc.wait(timeout=10)

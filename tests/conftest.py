@@ -8,15 +8,25 @@ used untouched; otherwise a minimal deterministic shim is installed in
 and the properties still run over seeded random draws (boundary values
 first, then uniform samples).  The shim does no shrinking — it exists
 so `PYTHONPATH=src python -m pytest -q` runs green in minimal
-environments, not to replace hypothesis in CI.
+environments, not to replace hypothesis in CI.  With the real package,
+the default settings profile has no per-example deadline: the first
+example of a property pays for JIT compilation, which a wall-clock
+deadline would report as a flaky failure.
+
+Every test's call phase runs under a time limit (``DEFAULT_TIME_LIMIT_S``,
+or ``@pytest.mark.time_limit(seconds)``): a test past its limit fails
+with its id, and a hang that never returns to Python still dumps every
+thread's stack shortly after.
 """
 
 from __future__ import annotations
 
 
+import faulthandler
 import os
 import random
 import re
+import signal
 import sys
 import types
 
@@ -31,8 +41,26 @@ from repro.compile_cache import use_compile_cache
 # their round timeouts on a cold runner.
 use_compile_cache()
 
+#: seconds a test's call phase may run unless it carries a time_limit
+DEFAULT_TIME_LIMIT_S = 120
+_STDERR_FD = 2
+
 
 def pytest_configure(config):
+    global _STDERR_FD
+    # the terminal's stderr, not the capture of the test that hangs:
+    # a process that never returns would lose its captured output
+    _STDERR_FD = os.dup(sys.__stderr__.fileno())
+    config.addinivalue_line(
+        "markers",
+        "time_limit(seconds): fail the test if its call phase runs "
+        f"longer than this (default {DEFAULT_TIME_LIMIT_S} s)")
+    # deselected by default (pyproject addopts); CI's net-integration job
+    # (-m "net or slow") and `-m slow` run them
+    config.addinivalue_line(
+        "markers",
+        "slow: over a minute alone on a CPU runner; not in the default "
+        "selection")
     # kernel differential tests carry the "kernels" marker so CI can run
     # them as a dedicated interpret-mode job (-m kernels); see
     # .github/workflows/ci.yml and DESIGN.md §7
@@ -64,6 +92,32 @@ def pytest_configure(config):
         "escrow audit, upload probes)")
 
 
+class TimeLimitExceeded(BaseException):
+    """A test ran past its time limit.  Not an ``Exception``, so neither
+    the test nor hypothesis (which would shrink on it) catches it."""
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_call(item):
+    marker = item.get_closest_marker("time_limit")
+    limit = marker.args[0] if marker else DEFAULT_TIME_LIMIT_S
+
+    def expire(signum, frame):
+        raise TimeLimitExceeded(
+            f"{item.nodeid} exceeded its time limit of {limit} s")
+
+    previous = signal.signal(signal.SIGALRM, expire)
+    signal.setitimer(signal.ITIMER_REAL, limit)
+    # a hang inside C code never runs the handler above
+    faulthandler.dump_traceback_later(limit + 30, file=_STDERR_FD)
+    try:
+        return (yield)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
+
+
 @pytest.fixture
 def net_log_dir(tmp_path, request):
     """Per-test coordinator/party log directory.
@@ -87,7 +141,7 @@ def net_log_dir(tmp_path, request):
     return sub
 
 try:
-    import hypothesis  # noqa: F401  (real package wins)
+    import hypothesis  # real package wins
 except ImportError:
     _DEFAULT_EXAMPLES = 20
 
@@ -159,3 +213,6 @@ except ImportError:
 
     sys.modules["hypothesis"] = _hyp
     sys.modules["hypothesis.strategies"] = _st
+else:
+    hypothesis.settings.register_profile("repro", deadline=None)
+    hypothesis.settings.load_profile("repro")

@@ -96,9 +96,11 @@ def test_flsimulation_accepts_spec_directly():
 
 
 def test_run_scenario_accepts_spec():
+    # spec plumbing only: VSS is the subject of test_vss_adversarial.py,
+    # test_relay_hardening_props.py and test_scenarios.py
     spec = ExperimentSpec(
         n=4, m=3, epochs=2, local_steps=1, scheme="shamir",
-        shamir_degree=1, vss=True, seed=1,
+        shamir_degree=1, vss=False, seed=1,
         scenario=ScenarioConfig(name="spec-smoke", batch_size=16,
                                 samples_per_party=40))
     rec = run_scenario(spec)

@@ -10,7 +10,7 @@ from repro.core import committee
 @given(st.integers(min_value=3, max_value=40),
        st.integers(min_value=1, max_value=5),
        st.integers(min_value=0, max_value=1000))
-@settings(max_examples=25, deadline=None)
+@settings(max_examples=25)
 def test_election_validity(n, m, seed):
     m = min(m, n)
     res = committee.elect(n=n, m=m, b=16, seed=seed)

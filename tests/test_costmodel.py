@@ -15,7 +15,7 @@ from repro.fl.simulation import FLSimulation
        st.integers(min_value=1, max_value=10**6),
        st.integers(min_value=1, max_value=16),
        st.integers(min_value=1, max_value=100))
-@settings(max_examples=60, deadline=None)
+@settings(max_examples=60)
 def test_expanded_forms_match(n, e, s, m, b):
     p = CostParams(n=n, e=e, s=s, m=m, b=b)
     assert costmodel.twophase_msg_num(p) == costmodel.expand_eq7(p)
@@ -23,7 +23,7 @@ def test_expanded_forms_match(n, e, s, m, b):
 
 
 @given(st.integers(min_value=8, max_value=256))
-@settings(max_examples=20, deadline=None)
+@settings(max_examples=20)
 def test_two_phase_beats_p2p_at_scale(n):
     """The paper's claim: for m << n the two-phase protocol wins."""
     p = CostParams(n=n, e=15, s=242, m=3, b=10)

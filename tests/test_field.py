@@ -12,7 +12,7 @@ FP = st.integers(min_value=0, max_value=field.MERSENNE_P_INT - 1)
 
 @given(st.lists(U32, min_size=1, max_size=64),
        st.lists(U32, min_size=1, max_size=64))
-@settings(max_examples=50, deadline=None)
+@settings(max_examples=50)
 def test_mulhilo32_matches_uint64(xs, ys):
     n = min(len(xs), len(ys))
     a = np.array(xs[:n], np.uint32)
@@ -27,7 +27,7 @@ def test_mulhilo32_matches_uint64(xs, ys):
 
 @given(st.lists(FP, min_size=1, max_size=64),
        st.lists(FP, min_size=1, max_size=64))
-@settings(max_examples=50, deadline=None)
+@settings(max_examples=50)
 def test_fmul_fadd_match_oracle(xs, ys):
     n = min(len(xs), len(ys))
     a = np.array(xs[:n], np.uint32)
@@ -41,7 +41,7 @@ def test_fmul_fadd_match_oracle(xs, ys):
 
 
 @given(FP)
-@settings(max_examples=30, deadline=None)
+@settings(max_examples=30)
 def test_fsub_fneg_inverse(x):
     a = jnp.asarray([x], jnp.uint32)
     zero = field.fadd(a, field.fneg(a))
@@ -50,7 +50,7 @@ def test_fsub_fneg_inverse(x):
 
 
 @given(st.integers(min_value=1, max_value=field.MERSENNE_P_INT - 1))
-@settings(max_examples=20, deadline=None)
+@settings(max_examples=20)
 def test_finv(x):
     a = jnp.asarray([x], jnp.uint32)
     one = field.fmul(a, field.finv(a))

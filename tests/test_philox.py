@@ -10,7 +10,7 @@ U32 = st.integers(min_value=0, max_value=2**32 - 1)
 
 
 @given(U32, U32, st.integers(min_value=1, max_value=32))
-@settings(max_examples=25, deadline=None)
+@settings(max_examples=25)
 def test_matches_numpy_oracle(k0, k1, n):
     counters = np.arange(4 * n, dtype=np.uint32).reshape(n, 4)
     got = np.asarray(philox.philox_4x32(jnp.asarray(counters),

@@ -203,7 +203,7 @@ def test_aggregate_unknown_party_id_typed_error():
 # hypothesis properties: dirichlet_partition
 # ---------------------------------------------------------------------------
 
-@settings(max_examples=25, deadline=None)
+@settings(max_examples=25)
 @given(st.integers(min_value=0, max_value=2**31 - 1),
        st.integers(min_value=2, max_value=6),
        st.sampled_from([10, 50, 100, 500]))
@@ -220,7 +220,7 @@ def test_dirichlet_partition_is_a_label_partition(seed, n_parties,
         "partition must cover every index exactly once"
 
 
-@settings(max_examples=25, deadline=None)
+@settings(max_examples=25)
 @given(st.integers(min_value=0, max_value=2**31 - 1),
        st.integers(min_value=2, max_value=5))
 def test_dirichlet_partition_seed_deterministic(seed, n_parties):
@@ -230,7 +230,7 @@ def test_dirichlet_partition_seed_deterministic(seed, n_parties):
     assert all(np.array_equal(x, y) for x, y in zip(a, b))
 
 
-@settings(max_examples=25, deadline=None)
+@settings(max_examples=25)
 @given(st.integers(min_value=0, max_value=2**31 - 1),
        st.integers(min_value=2, max_value=4))
 def test_dirichlet_partition_large_alpha_no_empty_party(seed, n_parties):
@@ -246,7 +246,7 @@ def test_dirichlet_partition_large_alpha_no_empty_party(seed, n_parties):
 # hypothesis properties: dealer blame in resolve_outcome
 # ---------------------------------------------------------------------------
 
-@settings(max_examples=40, deadline=None)
+@settings(max_examples=40)
 @given(st.integers(min_value=2, max_value=8),
        st.integers(min_value=0, max_value=2**31 - 1),
        st.integers(min_value=0, max_value=2**31 - 1),

@@ -228,6 +228,24 @@ def test_wire_register_resume_and_stale_session_rejection():
 
 
 @pytest.mark.net
+def test_wire_stop_returns_after_drop_and_reregister():
+    """Regression: the coordinator closes a connection whose read loop
+    ended.  Server.wait_closed() waits for every accepted connection
+    (Python >= 3.12), so a dropped socket left open hung stop()."""
+    with _Hub(n=2) as hub:
+        p1 = _RawParty(hub.port)
+        assert p1.hello(0).msg_type == MsgType.WELCOME
+        p1.close()
+        hub.wait_conn_dead(0)
+        p2 = _RawParty(hub.port)
+        assert p2.hello(0).msg_type == MsgType.WELCOME
+        t0 = time.monotonic()
+        hub._run(hub.co.stop(), timeout=2.0)
+        assert time.monotonic() - t0 < 2.0
+        p2.close()
+
+
+@pytest.mark.net
 def test_wire_resume_never_registered_pid_typed_error():
     """Raw-socket regression for the coordinator's resume path: a
     HELLO presenting a session id for a pid that never registered gets

@@ -13,7 +13,7 @@ from repro.core.fixed_point import FixedPointConfig
 
 @given(st.integers(min_value=1, max_value=12),
        st.integers(min_value=0, max_value=2**31))
-@settings(max_examples=25, deadline=None)
+@settings(max_examples=25)
 def test_additive_roundtrip(m, seed_val):
     rng = np.random.RandomState(seed_val % 100000)
     v = rng.randint(0, 2**32, size=257, dtype=np.uint64).astype(np.uint32)
@@ -25,7 +25,7 @@ def test_additive_roundtrip(m, seed_val):
 
 
 @given(st.integers(min_value=2, max_value=8))
-@settings(max_examples=10, deadline=None)
+@settings(max_examples=10)
 def test_additive_single_share_reveals_nothing_structural(m):
     """Shares of two different secrets have identical marginal streams
     for the mask shares (they ARE the Philox stream)."""
@@ -41,7 +41,7 @@ def test_additive_single_share_reveals_nothing_structural(m):
 
 @given(st.integers(min_value=1, max_value=6),
        st.integers(min_value=0, max_value=10000))
-@settings(max_examples=20, deadline=None)
+@settings(max_examples=20)
 def test_shamir_roundtrip(m, seed_val):
     rng = np.random.RandomState(seed_val)
     v = rng.randint(0, MERSENNE_P_INT, size=130,
@@ -70,7 +70,7 @@ def test_shamir_threshold_subsets():
 
 @given(st.integers(min_value=2, max_value=10),
        st.sampled_from(["additive", "shamir"]))
-@settings(max_examples=12, deadline=None)
+@settings(max_examples=12)
 def test_secure_mean_equals_plain_mean(n, scheme):
     rng = np.random.RandomState(n)
     flats = [jnp.asarray(rng.randn(101).astype(np.float32))

@@ -82,6 +82,55 @@ def _party_shares(agg, flat, seed_words, stream, counter_base):
                         counter_base=counter_base)
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _kernel_shares(agg, interpret, flats, seed_words, stream_lo, stream_hi,
+                   row_base):
+    """``[l, D] -> [l, m, D]`` on the kernel path: the ``[l, 2]`` party
+    keys and the fused share kernel in one executable.
+
+    Everything that changes per round or per seed (seed words, stream
+    words, counter-row offset) is an operand, so one compile serves
+    every round of a shape."""
+    from repro.kernels.shamir.ops import _shamir_share_batch_jit
+    from repro.kernels.share_gen.ops import _share_gen_batch_jit, unpad_flat
+    keys = jax.vmap(lambda lo: jnp.stack(
+        philox.derive_key(seed_words, (lo, stream_hi))))(stream_lo)
+    block_rows = 8 if interpret else 64
+    if agg.scheme == SCHEME_ADDITIVE:
+        stacks, _ = _share_gen_batch_jit(
+            flats, agg.m, keys, agg.fp, 0, block_rows, False, interpret,
+            "flat", row_base)
+    else:
+        stacks, _ = _shamir_share_batch_jit(
+            flats, agg.m, keys, agg.fp, agg.shamir_degree, 0, block_rows,
+            False, interpret, "flat", row_base)
+    return unpad_flat(stacks, flats.shape[-1])
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _kernel_mean(agg, interpret, points, member_sums, n):
+    """``[k, D]`` member sums -> ``[D]`` mean on the kernel path, in one
+    executable.
+
+    The kernels decode the *sum* (``n=1``: the in-kernel unscale is an
+    exact power-of-two multiply) and the mean divides by ``n``, a
+    float32 operand: with a constant divisor XLA would fold the two
+    divisions into one reciprocal multiply, 1 ulp off ``decode_mean``'s
+    float sequence, which the oracle path keeps."""
+    from repro.kernels.reconstruct.ops import _reconstruct_jit
+    from repro.kernels.shamir.ops import _shamir_reconstruct_jit
+    from repro.kernels.share_gen.ops import pad_to_tiles, unpad_flat
+    block_rows = 8 if interpret else 64
+    tiled, d = pad_to_tiles(member_sums, block_rows)
+    if agg.scheme == SCHEME_ADDITIVE:
+        out = _reconstruct_jit(tiled, 1, agg.fp, block_rows, False,
+                               interpret)
+    else:
+        out = _shamir_reconstruct_jit(tiled, 1, agg.fp, points, block_rows,
+                                      False, interpret)
+    return unpad_flat(out, d) / n
+
+
 def flatten_pytree(tree):
     """Pytree of float arrays -> (flat float32 vector, unflatten fn)."""
     leaves, treedef = jax.tree_util.tree_flatten(tree)
@@ -181,9 +230,10 @@ class SecureAggregator:
         """
         from repro.kernels import dispatch
         flats = jnp.asarray(flats, dtype=jnp.float32)
-        ids = jnp.asarray(np.asarray(party_ids), dtype=jnp.uint32)
-        stream_lo = jnp.uint32((round_index << 24) & 0xFFFFFFFF) | ids
-        stream_hi = (round_index << 24) >> 32
+        stream_lo = (np.uint32((round_index << 24) & 0xFFFFFFFF)
+                     | np.asarray(party_ids, dtype=np.uint32))
+        stream_hi = np.uint32((round_index << 24) >> 32)
+        seed_words = philox.int_words(seed)
         elem_base = int(elem_base)
         if elem_base % CHUNK_ALIGN != 0 or elem_base < 0:
             raise ValueError(
@@ -192,45 +242,20 @@ class SecureAggregator:
 
         dec = dispatch.decide(hot_path=True, forced=self.kernel_backend)
         if not dec.use_ref:
-            return self._make_shares_batch_kernel(flats, stream_lo,
-                                                  stream_hi, seed, dec,
-                                                  elem_base)
+            return _kernel_shares(self, dec.interpret, flats, seed_words,
+                                  stream_lo, stream_hi,
+                                  np.uint32(elem_base // 128))
 
         # one jitted call per party: an eager vmap retraces ~2,000 ops
-        # per call, and jitted over parties (vector keys) XLA's CPU
-        # backend runs the Philox rounds ~1,000x slower than per party
-        seed_words = philox.int_words(seed)
-        stream_hi = np.uint32(stream_hi)
+        # per call, and with the element-wide masks jitted over parties
+        # (vector keys) XLA's CPU backend runs their Philox rounds
+        # ~1,000x slower than per party.  The kernel path above vectorises
+        # only the l key blocks, which is cheap on every backend.
         counter_base = np.uint32(elem_base // 4)
         return jnp.stack([
             _party_shares(self, flat, seed_words, (lo, stream_hi),
                           counter_base)
-            for flat, lo in zip(np.asarray(flats), np.asarray(stream_lo))])
-
-    def _make_shares_batch_kernel(self, flats, stream_lo, stream_hi,
-                                  seed: int, dec, elem_base: int = 0):
-        """Fused-kernel twin of the vmap path (same keys, same bits)."""
-        from repro.kernels.share_gen import share_gen_batch, unpad_flat
-        from repro.kernels.shamir import shamir_share_batch
-        k0s, k1s = jax.vmap(
-            lambda lo: philox.derive_key(seed, (lo, stream_hi)))(stream_lo)
-        keys = jnp.stack([k0s, k1s], axis=1)
-        block_rows = 64 if dec.mode == "compiled" else 8
-        # a run-time kernel scalar: every element chunk shares one compile
-        row_base = elem_base // 128
-        # forced=dec.mode: the outer decision is authoritative — without
-        # it the inner op re-consults the env var, which would invert
-        # the documented per-object-over-env precedence
-        if self.scheme == SCHEME_ADDITIVE:
-            stacks, d = share_gen_batch(
-                flats, self.m, keys, self.fp, block_rows=block_rows,
-                layout="flat", forced=dec.mode, row_base=row_base)
-        else:
-            stacks, d = shamir_share_batch(
-                flats, self.m, keys, self.fp, degree=self.shamir_degree,
-                block_rows=block_rows, layout="flat", forced=dec.mode,
-                row_base=row_base)
-        return unpad_flat(stacks, d)
+            for flat, lo in zip(np.asarray(flats), stream_lo)])
 
     def sum_shares_batch(self, flats, *, seed: int, party_ids,
                          round_index: int = 0, chunk: int = PARTY_CHUNK,
@@ -427,24 +452,9 @@ class SecureAggregator:
         if dec.use_ref:
             return self.decode_mean(
                 self.reconstruct_sum(member_sums, points), n)
-        from repro.kernels.share_gen import pad_to_tiles, unpad_flat
-        block_rows = 64 if dec.mode == "compiled" else 8
-        tiled, d = pad_to_tiles(member_sums, block_rows)
-        # The kernels are called with n=1 (decoded *sum*: the in-kernel
-        # unscale is an exact power-of-two multiply) and the 1/n mean is
-        # applied eagerly here — inside jit, XLA folds the two constant
-        # divisions into one reciprocal multiply, which is 1 ulp off the
-        # eager decode_mean sequence the pre-dispatch oracle path uses.
-        if self.scheme == SCHEME_ADDITIVE:
-            from repro.kernels.reconstruct import reconstruct as rec_kernel
-            out = rec_kernel(tiled, 1, self.fp, block_rows=block_rows,
-                             forced=dec.mode)
-        else:
-            from repro.kernels.shamir import shamir_reconstruct
-            out = shamir_reconstruct(tiled, 1, self.fp, points=points,
-                                     block_rows=block_rows,
-                                     forced=dec.mode)
-        return unpad_flat(out, d) / float(n)
+        # n enters as an operand, never a constant (see _kernel_mean)
+        return _kernel_mean(self, dec.interpret, points, member_sums,
+                            np.float32(n))
 
     # -- one-shot reference path (no transport; used by tests) -----------
 

@@ -212,10 +212,14 @@ def test_aggregator_regression_pinned_to_pre_dispatch(scheme, round_index):
     np.testing.assert_array_equal(np.asarray(mean), np.asarray(mean_want))
 
 
+@pytest.mark.parametrize("round_index", [7, 300])
 @pytest.mark.parametrize("scheme,degree", [("additive", None),
                                            ("shamir", None), ("shamir", 1)])
-def test_aggregator_modes_bit_identical(scheme, degree):
-    """ref / interpret dispatch modes produce identical bits end-to-end."""
+def test_aggregator_modes_bit_identical(scheme, degree, round_index):
+    """ref / interpret dispatch modes produce identical bits end-to-end.
+
+    At round 300 the high stream word is nonzero; n = 5 is no power of
+    two, so a reciprocal folded into the mean's division would show."""
     _require_interpret()
     rng = np.random.RandomState(1)
     flats = jnp.asarray(rng.randn(5, 777).astype(np.float32))
@@ -226,7 +230,7 @@ def test_aggregator_modes_bit_identical(scheme, degree):
     for mode, agg in aggs.items():
         stacks = agg.make_shares_batch(flats, seed=11,
                                        party_ids=np.arange(5),
-                                       round_index=7)
+                                       round_index=round_index)
         sums = agg.reduce_party_shares(stacks)
         outs[mode] = (np.asarray(stacks),
                       np.asarray(agg.reconstruct_mean(sums, 5)))
@@ -236,13 +240,44 @@ def test_aggregator_modes_bit_identical(scheme, degree):
         sums = aggs["ref"].reduce_party_shares(
             aggs["ref"].make_shares_batch(flats, seed=11,
                                           party_ids=np.arange(5),
-                                          round_index=7))
+                                          round_index=round_index))
         sub = jnp.asarray([0, 2])
         np.testing.assert_array_equal(
             np.asarray(aggs["ref"].reconstruct_mean(sums[sub], 5,
                                                     points=(1, 3))),
             np.asarray(aggs["interpret"].reconstruct_mean(sums[sub], 5,
                                                           points=(1, 3))))
+
+
+@pytest.mark.parametrize("scheme", ["additive", "shamir"])
+def test_kernel_path_compiles_nothing_after_first_round(scheme):
+    """Seed, round and chunk offset are operands of the kernel path's
+    executables: after round 0 no round (300 has a nonzero high stream
+    word) and no new seed compiles an executable."""
+    _require_interpret()
+    rng = np.random.RandomState(3)
+    flats = jnp.asarray(rng.randn(3, 640).astype(np.float32))
+    agg = SecureAggregator(scheme=scheme, m=3, kernel_backend="interpret")
+
+    def mean(seed, round_index):
+        return agg.aggregate_stream(
+            flats, seed=seed, party_ids=np.arange(3),
+            round_index=round_index, chunk_elems=256).block_until_ready()
+
+    mean(21, 0)
+    compiled = []
+
+    def listen(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        for seed, round_index in ((21, 1), (21, 300), (22, 1)):
+            mean(seed, round_index)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert compiled == []
 
 
 @pytest.mark.parametrize("mode", ["ref", "interpret"])

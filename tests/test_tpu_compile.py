@@ -5,7 +5,9 @@ the TPU compiler installed with JAX can, for a topology it is only told
 about.  Each test lowers one kernel at the widths ``chip_smoke.py`` runs
 (D = 2^22, m = 3) and compiles it for one v5e chip.  The batched share
 kernels hold every party's keys in SMEM, so they also compile at the
-party engine's default party chunk.
+party engine's default party chunk.  The party engine's own executables
+(keys and share kernel; the reconstruct epilogue) compile at its
+benchmark round's 16 parties.
 """
 
 import jax
@@ -13,7 +15,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.aggregation import PARTY_CHUNK
+from repro.core.aggregation import (PARTY_CHUNK, SecureAggregator,
+                                    _kernel_mean, _kernel_shares)
 from repro.core.fixed_point import DEFAULT_FIELD, DEFAULT_RING
 from repro.kernels.reconstruct.ops import _reconstruct_jit
 from repro.kernels.shamir.ops import (_shamir_reconstruct_jit,
@@ -23,6 +26,8 @@ from repro.kernels.share_gen.ops import _share_gen_batch_jit, _share_gen_jit
 from repro.kernels.verify_shares.ops import _verify_shares_jit
 
 D, M, PARTIES = 1 << 22, 3, 4
+#: parties of the party engine's benchmark round
+ENGINE_L = 16
 ROWS = D // 128
 
 
@@ -53,44 +58,67 @@ def no_compile_cache():
 def _kernel_case(name, spec, parties=PARTIES, d_batch=D):
     """(jitted op, keyword arguments) of one kernel's compiled path."""
     u32 = jnp.uint32
+    compiled = dict(use_ref=False, interpret=False)
     flat = spec((D,), jnp.float32)
     flats = spec((parties, d_batch), jnp.float32)
     keys, scalar = spec((parties, 2), u32), spec((), u32)
     stack = spec((M, ROWS, 128), u32)
+    engine_flats = spec((ENGINE_L, D), jnp.float32)
     return {
         "share_gen": (_share_gen_jit, dict(
             flat=flat, key0=scalar, key1=scalar, m=M, cfg=DEFAULT_RING,
-            hi_base=0, block_rows=64, layout="tiled")),
+            hi_base=0, block_rows=64, layout="tiled", **compiled)),
         "share_gen_batch": (_share_gen_batch_jit, dict(
             flats=flats, keys=keys, row_base=scalar, m=M, cfg=DEFAULT_RING,
-            hi_base=0, block_rows=64, layout="flat")),
+            hi_base=0, block_rows=64, layout="flat", **compiled)),
         "shamir_share": (_shamir_share_jit, dict(
             flat=flat, key0=scalar, key1=scalar, m=M, cfg=DEFAULT_FIELD,
-            degree=None, hi_base=0, block_rows=64, layout="tiled")),
+            degree=None, hi_base=0, block_rows=64, layout="tiled",
+            **compiled)),
         "shamir_share_batch": (_shamir_share_batch_jit, dict(
             flats=flats, keys=keys, row_base=scalar, m=M,
             cfg=DEFAULT_FIELD, degree=None, hi_base=0, block_rows=64,
-            layout="flat")),
+            layout="flat", **compiled)),
         "reconstruct": (_reconstruct_jit, dict(
-            shares=stack, n=1, cfg=DEFAULT_RING, block_rows=64)),
+            shares=stack, n=1, cfg=DEFAULT_RING, block_rows=64,
+            **compiled)),
         "shamir_reconstruct": (_shamir_reconstruct_jit, dict(
             member_sums=stack, n=1, cfg=DEFAULT_FIELD, points=None,
-            block_rows=64)),
+            block_rows=64, **compiled)),
         "verify_shares": (_verify_shares_jit, dict(
             rows=spec((M, D), u32), commits_em=spec((D, M, 2), u32),
-            points=(1, 2, 3), block_rows=8)),
+            points=(1, 2, 3), block_rows=8, **compiled)),
+        # the party engine's kernel path: keys + share kernel, and the
+        # reconstruct epilogue, each one executable
+        "engine_shares": (_kernel_shares, dict(
+            agg=SecureAggregator(m=M), interpret=False, flats=engine_flats,
+            seed_words=(scalar, scalar), stream_lo=spec((ENGINE_L,), u32),
+            stream_hi=scalar, row_base=scalar)),
+        "engine_shamir_shares": (_kernel_shares, dict(
+            agg=SecureAggregator(scheme="shamir", m=M), interpret=False,
+            flats=engine_flats, seed_words=(scalar, scalar),
+            stream_lo=spec((ENGINE_L,), u32), stream_hi=scalar,
+            row_base=scalar)),
+        "engine_mean": (_kernel_mean, dict(
+            agg=SecureAggregator(m=M), interpret=False, points=None,
+            member_sums=spec((M, D), u32), n=spec((), jnp.float32))),
+        "engine_shamir_mean": (_kernel_mean, dict(
+            agg=SecureAggregator(scheme="shamir", m=M), interpret=False,
+            points=None, member_sums=spec((M, D), u32),
+            n=spec((), jnp.float32))),
     }[name]
 
 
 @pytest.mark.parametrize("name", [
     "share_gen", "share_gen_batch", "shamir_share", "shamir_share_batch",
-    "reconstruct", "shamir_reconstruct", "verify_shares"])
+    "reconstruct", "shamir_reconstruct", "verify_shares", "engine_shares",
+    "engine_shamir_shares", "engine_mean", "engine_shamir_mean"])
 def test_mpc_kernel_compiles_for_v5e(name, one_chip, no_compile_cache):
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     fn, kwargs = _kernel_case(name, spec)
-    compiled = fn.lower(use_ref=False, interpret=False, **kwargs).compile()
+    compiled = fn.lower(**kwargs).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -103,7 +131,7 @@ def test_batch_kernel_compiles_at_party_chunk(name, one_chip,
 
     fn, kwargs = _kernel_case(name, spec, parties=PARTY_CHUNK,
                               d_batch=64 * 128)
-    compiled = fn.lower(use_ref=False, interpret=False, **kwargs).compile()
+    compiled = fn.lower(**kwargs).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
